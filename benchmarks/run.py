@@ -99,6 +99,8 @@ def main(argv=None) -> None:
                     help="write the fleet_frontier structured perf record "
                          "(e.g. reports/BENCH_fleet_frontier.json)")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.enable()
 
     modules = [m for m in MODULES if args.only is None or args.only in m]
     if not modules:

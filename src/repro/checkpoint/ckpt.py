@@ -153,10 +153,7 @@ class CheckpointManager:
     directory: str
     keep: int = 3
     async_save: bool = False
-    _thread: threading.Thread | None = None
-
-    def __post_init__(self):
-        os.makedirs(self.directory, exist_ok=True)
+    _thread: threading.Thread | None = None   # the directory appears on save
 
     # -- save ---------------------------------------------------------------
     def save(self, step: int, state: dict[str, Any],
@@ -236,6 +233,8 @@ class CheckpointManager:
     # -- restore --------------------------------------------------------------
     def list_steps(self) -> list[int]:
         out = []
+        if not os.path.isdir(self.directory):
+            return out
         for d in os.listdir(self.directory):
             if d.startswith("step_") and os.path.exists(
                     os.path.join(self.directory, d, ".complete")):

@@ -553,7 +553,8 @@ def sharded_control_round(controller: InGraphRailController, mesh,
                     _ops.chip_specs(frame, n_chips, axis_name),
                     _ops.chip_specs(sor_state, n_chips, axis_name))
         out_specs = (in_specs[0], in_specs[2], P(), P())
-        return _ops._shard_map(_local, mesh, in_specs, out_specs)(
+        return jax.shard_map(_local, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(
             plane, frame, sor_state)
 
     return round

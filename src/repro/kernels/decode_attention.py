@@ -14,6 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiles import pad_to, seq_tile
+
 DEFAULT_BK = 512
 NEG_INF = -1e30
 
@@ -59,12 +61,13 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 def decode_attention(q, k, v, lengths, *, group=1, bk=DEFAULT_BK,
                      interpret=False):
-    """q [B,1,Hq,Dh]; k/v [B,S,Hkv,Dh]; lengths [B] -> [B,1,Hq,Dh]."""
+    """q [B,1,Hq,Dh]; k/v [B,S,Hkv,Dh]; lengths [B] -> [B,1,Hq,Dh].
+    Any S: a cache that does not tile is zero-padded here, and `lengths`
+    (at most S) already masks the padded slots."""
     B, _, Hq, Dh = q.shape
     S, Hkv = k.shape[1], k.shape[2]
-    bk = min(bk, S)
-    if S % bk:
-        raise ValueError(f"S={S} must tile by bk={bk}")
+    bk, S = seq_tile(S, bk)
+    k, v = pad_to(k, 1, S), pad_to(v, 1, S)
     n_kb = S // bk
     scale = 1.0 / (Dh ** 0.5)
 
@@ -91,5 +94,6 @@ def decode_attention(q, k, v, lengths, *, group=1, bk=DEFAULT_BK,
                         pltpu.VMEM((1, Dh), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((B * Hq, 1, Dh), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(len_rep, qf, kf, vf)
     return jnp.swapaxes(o.reshape(B, Hq, 1, Dh), 1, 2)

@@ -150,6 +150,7 @@ def sor_fit(x, y, w, log10_bound, guard, *, min_slope: float,
         out_specs=(lane_spec,) * 6,
         out_shape=(out_shape,) * 6,
         interpret=interpret,
+        name="sor_fit",
     )(xm, ym, wm, bm, gm)
     return tuple(o[0, :n] for o in outs)
 
@@ -185,6 +186,7 @@ def sor_accumulate(x, y, w, *, interpret: bool = False):
         out_specs=(out_spec,) * 5,
         out_shape=(out_shape,) * 5,
         interpret=interpret,
+        name="sor_accumulate",
     )(xm, ym, wm)
     return tuple(o[0, :n] for o in outs)
 
@@ -207,5 +209,6 @@ def fleet_reduce(x, *, interpret: bool = False):
         out_specs=(out_spec, out_spec, out_spec),
         out_shape=(out_shape, out_shape, out_shape),
         interpret=interpret,
+        name="fleet_reduce",
     )(mat)
     return mx[0, :n_fields], mn[0, :n_fields], sm[0, :n_fields]

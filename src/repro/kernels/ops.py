@@ -10,11 +10,13 @@ from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
+from repro.kernels.tiles import pad_to, seq_tile
 
 
 def _pallas_mode() -> str:
@@ -24,6 +26,22 @@ def _pallas_mode() -> str:
     if env == "off":
         return "off"
     return "native" if jax.default_backend() == "tpu" else "off"
+
+
+_TPU_KERNEL = re.compile(
+    r'%([A-Za-z_]\w*?)(?:\.[\w.]+)? = [^\n]*'
+    r'custom_call_target="tpu_custom_call"')
+
+
+_TRANSFORMS = re.compile(r"^(?:(?:jvp|transpose|vmap|batched)_)+|_+$")
+
+
+def pallas_kernels(hlo_text: str) -> set[str]:
+    """Names of the Pallas TPU kernels in a compiled program, from its text
+    (`jax.jit(f).lower(...).compile().as_text()`): each kernel is a
+    `tpu_custom_call` named after its `pallas_call(name=...)`, which jvp
+    and transpose decorate (`transpose_jvp_<name>__`) and this undoes."""
+    return {_TRANSFORMS.sub("", n) for n in _TPU_KERNEL.findall(hlo_text)}
 
 
 # ---------------------------------------------------------------------------
@@ -77,10 +95,36 @@ def _mamba2_fwd(x, dt, A, B, C, D, chunk, interpret, init_state):
     return out, (x, dt, A, B, C, D, init_state)
 
 
+def _mamba2_remat_reference(x, dt, A, B, C, D, init_state, *, chunk):
+    """`ref.mamba2_scan_reference` run over `chunk`-step pieces, each under
+    `jax.checkpoint`: its vjp keeps one state per piece and recomputes a
+    piece's per-step states while differentiating it, where the plain
+    sequential scan keeps all T of them ([T, B, H, N, P] f32 — 4 GiB per
+    layer at zamba2_1p2b's widths for 4 x 512 tokens). Padded steps have
+    dt = 0, which leaves the state as it was."""
+    T = x.shape[1]
+    c, t_pad = seq_tile(T, chunk)
+    n = t_pad // c
+
+    def pieces(a):
+        a = pad_to(a, 1, t_pad)
+        return jnp.moveaxis(a.reshape(a.shape[0], n, c, *a.shape[2:]), 1, 0)
+
+    @jax.checkpoint
+    def piece(s, xs):
+        xc, dtc, bc, cc = xs
+        y, s = ref.mamba2_scan_reference(xc, dtc, A, bc, cc, D, init_state=s)
+        return s, y
+
+    s, ys = jax.lax.scan(piece, init_state, tuple(map(pieces, (x, dt, B, C))))
+    y = jnp.moveaxis(ys, 0, 1).reshape(x.shape[0], t_pad, *x.shape[2:])
+    return y[:, :T], s
+
+
 def _mamba2_bwd(chunk, interpret, res, g):
     x, dt, A, B, C, D, init_state = res
     _, vjp = jax.vjp(
-        lambda *a: ref.mamba2_scan_reference(*a[:6], init_state=a[6]),
+        lambda *a: _mamba2_remat_reference(*a, chunk=chunk),
         x, dt, A, B, C, D,
         init_state if init_state is not None
         else jnp.zeros((x.shape[0], x.shape[2], B.shape[3], x.shape[3]),
@@ -247,16 +291,6 @@ def shard_chip_tree(tree, mesh, n_chips: int, axis_name: str = "chips"):
         lambda a, s: jax.device_put(a, NamedSharding(mesh, s)), tree, specs)
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-portable shard_map (jax >= 0.5 top-level vs experimental)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def sharded_fleet_reduce(x, *, mesh=None, axis_name: str = "chips",
                          use_shard_map: bool | None = None):
     """`fleet_reduce` for a fleet axis sharded across real devices.
@@ -284,5 +318,5 @@ def sharded_fleet_reduce(x, *, mesh=None, axis_name: str = "chips",
         return (jax.lax.pmax(mx, axis_name), jax.lax.pmin(mn, axis_name),
                 jax.lax.psum(sm, axis_name))
 
-    return _shard_map(local, mesh, in_specs=(P(axis_name),),
-                      out_specs=(P(), P(), P()))(x)
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(axis_name),),
+                         out_specs=(P(), P(), P()), check_vma=False)(x)

@@ -51,5 +51,6 @@ def quantize_int8(x, *, block: int = 256, interpret: bool = False):
         out_shape=(jax.ShapeDtypeStruct(mat.shape, jnp.int8),
                    jax.ShapeDtypeStruct((mat.shape[0], 1), jnp.float32)),
         interpret=interpret,
+        name="quantize_int8",
     )(mat)
     return q[:rows], s[:rows]

@@ -22,42 +22,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiles import pad_to, row_to_col, seq_tile
+
 DEFAULT_CHUNK = 64
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sfin_ref,
-            s_scr, *, chunk, n_chunks, n_heads):
+            s_scr, y_scr, *, chunk, n_chunks):
+    # the state is kept value-major, T = S^T [Dh_v, Dh_k], so each step
+    # needs k, w and u only as rows and v as one column:
+    #   y_t = r_t T^T + (r_t . (u * k_t)) v_t      T' = T * e^{w_t} + v_t^T k_t
     ci = pl.program_id(1)
-    h = pl.program_id(0) % n_heads
 
     @pl.when(ci == 0)
     def _init():
         s_scr[...] = s0_ref[0]
 
-    r = r_ref[0].astype(jnp.float32)    # [Lc, Dh]
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    w = w_ref[0].astype(jnp.float32)    # log-decay, <= 0
-    u = u_ref[h].astype(jnp.float32)    # [Dh]
+    u = u_ref[0].astype(jnp.float32)                        # [1, Dh]
 
-    def step(t, carry):
-        s, y = carry
-        rt = jax.lax.dynamic_slice_in_dim(r, t, 1, 0)        # [1, Dh]
-        kt = jax.lax.dynamic_slice_in_dim(k, t, 1, 0)
-        vt = jax.lax.dynamic_slice_in_dim(v, t, 1, 0)
-        wt = jax.lax.dynamic_slice_in_dim(w, t, 1, 0)
-        kv = kt.T * vt                                       # [Dh, Dh] rank-1
-        att = s + (u[:, None] * kv)
-        yt = jax.lax.dot_general(rt, att, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)  # [1,Dh]
-        s = s * jnp.exp(wt).T + kv
-        y = jax.lax.dynamic_update_slice_in_dim(y, yt, t, 0)
-        return s, y
+    def step(t, s):
+        row = pl.ds(t, 1)
+        rt = r_ref[0, row, :]                               # [1, Dh]
+        kt = k_ref[0, row, :]
+        vt = v_ref[0, row, :]
+        wt = w_ref[0, row, :]                               # log-decay, <= 0
+        kv = row_to_col(vt) * kt                            # [Dh_v, Dh_k]
+        att = s + kv * u
+        y_scr[row, :] = jax.lax.dot_general(
+            rt, att, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [1, Dh_v]
+        return s * jnp.exp(wt) + kv
 
-    y0 = jnp.zeros((chunk, r.shape[1]), jnp.float32)
-    s_fin, y = jax.lax.fori_loop(0, chunk, step, (s_scr[...], y0))
+    s_fin = jax.lax.fori_loop(0, chunk, step, s_scr[...])
     s_scr[...] = s_fin
-    y_ref[0] = y.astype(y_ref.dtype)
+    y_ref[0] = y_scr[...].astype(y_ref.dtype)
 
     @pl.when(ci == n_chunks - 1)
     def _emit():
@@ -67,38 +65,45 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sfin_ref,
 def rwkv6_scan(r, k, v, w, u, *, chunk=DEFAULT_CHUNK, init_state=None,
                interpret=False):
     """r,k,v,w [B,T,H,Dh] (w = log-decay <= 0); u [H,Dh].
-    Returns (y [B,T,H,Dh], final_state [B,H,Dh,Dh])."""
+    Returns (y [B,T,H,Dh], final_state [B,H,Dh,Dh]). Any T: a length that
+    does not tile is zero-padded, and padded steps (k = 0, w = 0) leave the
+    state untouched."""
     B, T, H, Dh = r.shape
-    chunk = min(chunk, T)
-    if T % chunk:
-        raise ValueError(f"T={T} must tile by chunk={chunk}")
-    n_chunks = T // chunk
+    chunk, t_pad = seq_tile(T, chunk)
+    n_chunks = t_pad // chunk
     if init_state is None:
         init_state = jnp.zeros((B, H, Dh, Dh), jnp.float32)
 
     def flat(a):
-        return jnp.swapaxes(a, 1, 2).reshape(B * H, T, Dh)
+        # f32 rows: the per-step row loads sit at dynamic sublane offsets,
+        # which the TPU compiler accepts for 32-bit types only
+        return jnp.swapaxes(pad_to(a, 1, t_pad), 1, 2).reshape(
+            B * H, t_pad, Dh).astype(jnp.float32)
 
-    s0 = init_state.reshape(B * H, Dh, Dh)
+    s0 = jnp.swapaxes(init_state, 2, 3).reshape(B * H, Dh, Dh)
     row = lambda bh, ci: (bh, ci, 0)
     y, sfin = pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk, n_chunks=n_chunks, n_heads=H),
+        functools.partial(_kernel, chunk=chunk, n_chunks=n_chunks),
         grid=(B * H, n_chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, Dh), row),
             pl.BlockSpec((1, chunk, Dh), row),
             pl.BlockSpec((1, chunk, Dh), row),
             pl.BlockSpec((1, chunk, Dh), row),
-            pl.BlockSpec(memory_space=pl.ANY),  # u [H, Dh]
+            pl.BlockSpec((1, 1, Dh), lambda bh, ci, h=H: (bh % h, 0, 0)),
             pl.BlockSpec((1, Dh, Dh), lambda bh, ci: (bh, 0, 0)),
         ],
         out_specs=(
             pl.BlockSpec((1, chunk, Dh), row),
             pl.BlockSpec((1, Dh, Dh), lambda bh, ci: (bh, 0, 0)),
         ),
-        scratch_shapes=[pltpu.VMEM((Dh, Dh), jnp.float32)],
-        out_shape=(jax.ShapeDtypeStruct((B * H, T, Dh), r.dtype),
+        scratch_shapes=[pltpu.VMEM((Dh, Dh), jnp.float32),
+                        pltpu.VMEM((chunk, Dh), jnp.float32)],
+        out_shape=(jax.ShapeDtypeStruct((B * H, t_pad, Dh), r.dtype),
                    jax.ShapeDtypeStruct((B * H, Dh, Dh), jnp.float32)),
         interpret=interpret,
-    )(flat(r), flat(k), flat(v), flat(w), jnp.asarray(u, jnp.float32), s0)
-    return jnp.swapaxes(y.reshape(B, H, T, Dh), 1, 2), sfin.reshape(B, H, Dh, Dh)
+        name="rwkv6_scan",
+    )(flat(r), flat(k), flat(v), flat(w),
+      jnp.asarray(u, jnp.float32).reshape(H, 1, Dh), s0)
+    y = jnp.swapaxes(y.reshape(B, H, t_pad, Dh), 1, 2)[:, :T]
+    return y, jnp.swapaxes(sfin.reshape(B, H, Dh, Dh), 2, 3)

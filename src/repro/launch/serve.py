@@ -1,6 +1,10 @@
 """Serving launcher: batched prefill+decode with the power plane.
 
     python -m repro.launch.serve --arch qwen2p5_14b --tiny --max-new 32
+    python -m repro.launch.serve --arch minicpm_2b --control-path in-graph \
+        --sor --prompt-len 512                    (published widths, one chip)
+
+Without `--tiny` the published CONFIG runs.
 """
 
 from __future__ import annotations
@@ -10,16 +14,20 @@ import argparse
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.core.control_plane import HostRailController, InGraphRailController
 from repro.core.hwspec import FleetSpec
 from repro.core.policy import POLICIES, WorstChipGate
 from repro.core.power_plane import StepProfile
+from repro.core.sor import SorConfig
 from repro.models import registry
 from repro.serve.engine import ServeEngine
 
+CACHE_ALIGN = 128   # decode-kernel tile: a cache of this multiple never pads
 
-def main():
+
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--tiny", action="store_true")
@@ -33,6 +41,12 @@ def main():
                     help="serve on an [n_chips] fleet plane with per-chip "
                          "process variation (0 = scalar single-chip)")
     ap.add_argument("--fleet-seed", type=int, default=0)
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights and prompts")
+    ap.add_argument("--sor", action="store_true",
+                    help="in-graph path: learn safe operating regions in "
+                         "the fused control round run after every token "
+                         "(core/sor.py)")
     ap.add_argument("--router", choices=("none", "headroom", "roundrobin"),
                     default="none",
                     help="route a seeded bursty traffic trace over the "
@@ -65,7 +79,7 @@ def main():
                          "held this many consecutive ticks (0 = off; "
                          "needs --router headroom — round-robin has no "
                          "migration planner)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.batch_cap < 0:
         ap.error(f"--batch-cap must be >= 0, got {args.batch_cap}")
     if args.migrate_after_ticks < 0:
@@ -77,13 +91,21 @@ def main():
     if args.migrate_after_ticks and args.router != "headroom":
         ap.error("--migrate-after-ticks needs the headroom router's "
                  "migration planner; pass --router headroom")
+    if args.sor and args.control_path != "in-graph":
+        ap.error("--sor learns in the in-graph control round; pass "
+                 "--control-path in-graph")
+    return args
 
-    cfg = get_config(args.arch, tiny=args.tiny or True)
+
+def build_engine(args):
+    """The configured model with random weights from `--seed`, behind a
+    `ServeEngine`. Returns (cfg, engine, n_params)."""
+    cfg = get_config(args.arch, tiny=args.tiny)
     if cfg.family == "encdec":
         raise SystemExit("whisper serving uses cross-attention prefill; see "
                          "tests/test_models_smoke.py::test_arch_decode_step_smoke")
     api = registry.build(cfg)
-    params = api.init(jax.random.PRNGKey(0))
+    params = api.init(jax.random.PRNGKey(args.seed))
     n = sum(p.size for p in jax.tree_util.tree_leaves(params))
 
     policy = POLICIES[args.policy]
@@ -92,7 +114,8 @@ def main():
     if fleet is not None:
         # fleet serving: gate every chip's decision on the worst chip
         policy = WorstChipGate(policy)
-    controller = (InGraphRailController(policy)
+    sor = SorConfig(ingest="frames") if args.sor else None
+    controller = (InGraphRailController(policy, sor=sor)
                   if args.control_path == "in-graph"
                   else HostRailController(policy,
                                           n_chips=max(args.fleet_chips, 1)))
@@ -113,14 +136,29 @@ def main():
         router = (HeadroomRouter(capacity=lanes, drain_pinned=False)
                   if args.router == "headroom"
                   else RoundRobinRouter(capacity=lanes))
+    max_len = -(-(args.prompt_len + args.max_new) // CACHE_ALIGN) * CACHE_ALIGN
     engine = ServeEngine(
-        cfg, params, max_len=args.prompt_len + args.max_new + 8,
+        cfg, params, max_len=max_len,
         batch_size=args.batch,
         prefill_profile=StepProfile(2.0 * n * args.batch * args.prompt_len,
                                     2.0 * n, 0.0),
         decode_profile=StepProfile(2.0 * n * args.batch, 2.0 * n, 0.0),
         controller=controller, fleet=fleet, router=router,
         batch_cap=args.batch_cap or None)
+    return cfg, engine, n
+
+
+def prompts_for(args, cfg) -> np.ndarray:
+    """Seeded random prompts, [batch, prompt_len] int32."""
+    return np.random.default_rng(args.seed).integers(
+        0, cfg.vocab_size, (args.batch, args.prompt_len)).astype(np.int32)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    compile_cache.enable()
+    cfg, engine, n = build_engine(args)
+    router = engine.router
     if router is not None:
         # routed serving: place a seeded bursty trace by per-rail headroom
         # (docs/serve.md) and report the per-request SLO ledger
@@ -145,9 +183,7 @@ def main():
         print("slo:", ledger.summary())
         print("summary:", engine.summary())
         return
-    prompts = np.random.default_rng(0).integers(
-        0, cfg.vocab_size, (args.batch, args.prompt_len)).astype(np.int32)
-    out = engine.generate(prompts, max_new_tokens=args.max_new)
+    out = engine.generate(prompts_for(args, cfg), max_new_tokens=args.max_new)
     print(f"{cfg.name} ({n/1e6:.1f}M): generated {out.shape} tokens")
     print("summary:", engine.summary())
 

@@ -226,6 +226,17 @@ def forward_train(params, batch, cfg: ModelConfig, *, remat: str = "full"):
     return total, {"ce_loss": loss, "moe_aux": aux}
 
 
+def forward_logits(params, tokens, cfg: ModelConfig):
+    """No-cache forward over a whole token sequence: tokens [B,T] -> logits
+    [B,T,V]. The reference that prefill followed by cached decode steps must
+    reproduce."""
+    x = embed_tokens(params, tokens, cfg)
+    B, T = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
+    x, _ = _run_blocks(params, x, cfg, positions, remat="none")
+    return logits_from(params, x, cfg)
+
+
 # ---------------------------------------------------------------------------
 # Decode: caches + single-token step
 # ---------------------------------------------------------------------------

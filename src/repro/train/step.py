@@ -443,5 +443,5 @@ def shard_map_ef_step(train_step, mesh, dp_axes=("data",)):
 
     in_specs = (rep, rep, rep, rep, batch_spec)
     out_specs = (rep, rep, rep, rep, rep)
-    # version shim shared with the sharded fleet reduction (ops._shard_map)
-    return ops._shard_map(mapped, mesh, in_specs, out_specs)
+    return jax.shard_map(mapped, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -43,7 +43,7 @@ class FaultConfig:
 @dataclasses.dataclass
 class TrainerConfig:
     total_steps: int
-    ckpt_every: int = 50
+    ckpt_every: int = 50             # 0 = never checkpoint
     ckpt_dir: str = "/tmp/repro_ckpt"
     async_ckpt: bool = True
     # Host-path (SW analogue) control plane: a RailController, or a bare
@@ -226,7 +226,8 @@ class Trainer:
             self.log.append_from(step, metrics["loss"], metrics,
                                  self.state["plane"])
             step += 1
-            if step % cfg.ckpt_every == 0 or step == cfg.total_steps:
+            if cfg.ckpt_every and (step % cfg.ckpt_every == 0
+                                   or step == cfg.total_steps):
                 self._save(step)
         return step
 

@@ -111,6 +111,42 @@ def test_mamba2_ssd_sweep(T, H, P, G, N, chunk):
                                rtol=1e-3, atol=2e-4)
 
 
+@pytest.mark.parametrize("with_state", [False, True])
+def test_mamba2_kernel_grads_match_reference(with_state):
+    """The kernel's custom vjp (the reference differentiated piece by piece
+    under remat) gives the sequential reference's gradients, at a length
+    that does not tile by the chunk."""
+    from repro.kernels import ops
+    ks = jax.random.split(KEY, 8)
+    Bt, T, H, P, G, N = 1, 100, 2, 16, 1, 16
+    x = jax.random.normal(ks[0], (Bt, T, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (Bt, T, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)))
+    Bm = jax.random.normal(ks[3], (Bt, T, G, N))
+    Cm = jax.random.normal(ks[4], (Bt, T, G, N))
+    D = jax.random.normal(ks[5], (H,))
+    s0 = (jax.random.normal(ks[6], (Bt, H, N, P)) if with_state else None)
+    gy = jax.random.normal(ks[7], (Bt, T, H, P))
+
+    def loss(scan):
+        def f(*a):
+            y, s = scan(*a)
+            return jnp.sum(y * gy) + jnp.sum(s)
+        return f
+
+    args = (x, dt, A, Bm, Cm, D) + ((s0,) if with_state else ())
+    n = len(args)
+    g1 = jax.grad(loss(lambda *a: ops._mamba2_kernel_vjp(
+        *a[:6], 32, True, a[6] if with_state else None)),
+        argnums=tuple(range(n)))(*args)
+    g2 = jax.grad(loss(lambda *a: ref.mamba2_scan_reference(
+        *a[:6], init_state=a[6] if with_state else None)),
+        argnums=tuple(range(n)))(*args)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
 def test_mamba2_ssd_initial_state_continuation():
     """Scanning [0:T] must equal scanning [0:T/2] then [T/2:T] with the
     carried state — the decode/prefill contract."""
@@ -159,3 +195,77 @@ def test_quant_codec_sweep(n, block, dtype):
     q2, s2 = ref.quantize_int8_reference(x, block=block)
     assert bool(jnp.all(q1 == q2))
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# lengths that do not tile: the wrappers pad, and padding changes nothing
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("T,causal", [(100, True), (200, True), (200, False)])
+def test_flash_attention_untiled_lengths(T, causal):
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (1, T, 4, 32))
+    k = jax.random.normal(ks[1], (1, T, 2, 32))
+    v = jax.random.normal(ks[2], (1, T, 2, 32))
+    g = jax.random.normal(ks[3], (1, T, 4, 32))
+
+    def f_kernel(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=causal, group=2,
+                                       interpret=True) * g)
+
+    def f_ref(q, k, v):
+        return jnp.sum(ref.mha_reference(q, k, v, causal=causal, group=2) * g)
+
+    out = flash_attention(q, k, v, causal=causal, group=2, interpret=True)
+    exp = ref.mha_reference(q, k, v, causal=causal, group=2)
+    assert out.shape == exp.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               rtol=2e-4, atol=2e-5)
+    g1 = jax.grad(f_kernel, argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-4)
+
+
+def test_decode_attention_untiled_cache():
+    ks = jax.random.split(KEY, 3)
+    B, S, Hq, Hkv, Dh = 2, 300, 4, 2, 64
+    q = jax.random.normal(ks[0], (B, 1, Hq, Dh))
+    k = jax.random.normal(ks[1], (B, S, Hkv, Dh))
+    v = jax.random.normal(ks[2], (B, S, Hkv, Dh))
+    lengths = jnp.array([S // 3, S], jnp.int32)
+    out = decode_attention(q, k, v, lengths, group=2, bk=128, interpret=True)
+    exp = ref.mha_reference(q, k, v, causal=False, group=2, lengths=lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp),
+                               rtol=2e-4, atol=2e-5)
+
+
+def test_scans_untiled_lengths():
+    """Padded scan steps leave the carried state untouched, so outputs and
+    final states match the oracles at a length that does not tile."""
+    ks = jax.random.split(KEY, 6)
+    Bt, T, H, P, G, N = 1, 200, 2, 32, 1, 16
+    x = jax.random.normal(ks[0], (Bt, T, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (Bt, T, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)))
+    Bm = jax.random.normal(ks[3], (Bt, T, G, N))
+    Cm = jax.random.normal(ks[4], (Bt, T, G, N))
+    D = jax.random.normal(ks[5], (H,))
+    got = mamba2_ssd(x, dt, A, Bm, Cm, D, interpret=True)
+    exp = ref.mamba2_scan_reference(x, dt, A, Bm, Cm, D)
+    for a, b in zip(got, exp):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=2e-4)
+
+    T, Dh = 50, 32
+    r, k, v = (jax.random.normal(kk, (Bt, T, H, Dh)) for kk in ks[:3])
+    w = -jnp.exp(jax.random.normal(ks[3], (Bt, T, H, Dh)))
+    u = jax.random.normal(ks[4], (H, Dh))
+    got = rwkv6_scan(r, k, v, w, u, chunk=32, interpret=True)
+    exp = ref.rwkv6_scan_reference(r, k, v, w, u)
+    for a, b in zip(got, exp):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=2e-4)
