@@ -485,8 +485,12 @@ class InGraphRailController:
         frame = as_frame(telemetry, state=plane)
         if _all_concrete((plane, frame, sor_state)):
             if self._round_jit is None:
+                # named, so that the device trace lists it as such
+                def control_round(p, f, s):
+                    return self.control_round(p, f, s)
+
                 self._round_jit = jax.jit(
-                    lambda p, f, s: self.control_round(p, f, s),
+                    control_round,
                     donate_argnums=(2,) if self.donate else ())
             plane, sor_state, request, env = self._round_jit(
                 plane, frame, sor_state)

@@ -190,11 +190,18 @@ class ServeEngine:
         self.shard_control = bool(shard_control)
         self._tick_cache: dict = {}   # (observe id, tick_s, bound) -> jit
 
-        self._decode = jax.jit(
-            lambda params, cache, batch: self.api.decode_fn(params, cache, batch))
-        self._prefill = (jax.jit(
-            lambda params, toks: self.api.prefill_fn(params, toks, max_len))
-            if self.api.prefill_fn else None)
+        self._calls = 0              # generate() calls, the spans' `call`
+
+        # named programs: the device trace lists each execution by the
+        # jitted function's name
+        def decode_step(params, cache, batch):
+            return self.api.decode_fn(params, cache, batch)
+
+        def prefill(params, toks):
+            return self.api.prefill_fn(params, toks, max_len)
+
+        self._decode = jax.jit(decode_step)
+        self._prefill = jax.jit(prefill) if self.api.prefill_fn else None
 
     @property
     def n_chips(self) -> int:
@@ -206,34 +213,39 @@ class ServeEngine:
         if self.controller is None:
             return
         c = self.controller
-        if getattr(c, "sor", None) is not None and hasattr(
-                c, "control_step_sor"):
-            if self._sor_state is None:
-                self._sor_state = c.init_sor(
-                    self.n_chips if self.plane.is_fleet else None)
-            # one fused control round per decision: observe + refit
-            # (amortized by refresh_every) + decide + arbitrate run
-            # as a single cached jitted program, so per-decision
-            # controller cost stays flat as the fleet grows
-            self.plane, self._sor_state = c.control_step_sor(
-                self.plane, frame, self._sor_state)
-        else:
-            self.plane = c.control_step(self.plane, frame)
+        with jax.profiler.TraceAnnotation("serve.control"):
+            if getattr(c, "sor", None) is not None and hasattr(
+                    c, "control_step_sor"):
+                if self._sor_state is None:
+                    self._sor_state = c.init_sor(
+                        self.n_chips if self.plane.is_fleet else None)
+                # one fused control round per decision: observe + refit
+                # (amortized by refresh_every) + decide + arbitrate run
+                # as a single cached jitted program, so per-decision
+                # controller cost stays flat as the fleet grows
+                self.plane, self._sor_state = c.control_step_sor(
+                    self.plane, frame, self._sor_state)
+            else:
+                self.plane = c.control_step(self.plane, frame)
 
     def _account(self, profile: StepProfile, n: int = 1):
         for _ in range(n):
-            if self.fleet_spec is not None:
-                self.plane, frame, m = account_fleet_and_observe(
-                    profile, self.plane, self.fleet_spec)
-            else:
-                self.plane, frame, m = account_and_observe(profile, self.plane)
-            # array-aware reductions (TelemetryLog's scalar-view convention):
-            # scalars pass through, [n_chips] metrics report the fleet mean
-            e = scalar_view(m["energy_step_j"])
-            self.stats.energy_j += e
-            self.stats.fleet_energy_j += e * self.n_chips
-            self.stats.model_time_s += scalar_view(m["t_step_s"])
-            self._control_tick(frame)
+            with jax.profiler.TraceAnnotation("serve.account"):
+                if self.fleet_spec is not None:
+                    self.plane, frame, m = account_fleet_and_observe(
+                        profile, self.plane, self.fleet_spec)
+                else:
+                    self.plane, frame, m = account_and_observe(profile,
+                                                               self.plane)
+                # array-aware reductions (TelemetryLog's scalar-view
+                # convention): scalars pass through, [n_chips] metrics report
+                # the fleet mean
+                with jax.profiler.TraceAnnotation("serve.sync"):
+                    e = scalar_view(m["energy_step_j"])
+                    self.stats.energy_j += e
+                    self.stats.fleet_energy_j += e * self.n_chips
+                    self.stats.model_time_s += scalar_view(m["t_step_s"])
+                self._control_tick(frame)
 
     def _worst_chip_pinned(self) -> bool:
         """Did the latest arbitration pin any chip at any requested rail's
@@ -272,37 +284,54 @@ class ServeEngine:
 
     def generate(self, prompts: np.ndarray, max_new_tokens: int,
                  eos_id: int | None = None) -> np.ndarray:
-        """prompts [B, Tp] int32 -> generated [B, max_new_tokens]."""
-        B, Tp = prompts.shape
-        assert B == self.batch_size, (B, self.batch_size)
-        toks = jnp.asarray(prompts, jnp.int32)
+        """prompts [B, Tp] int32 -> generated [B, max_new_tokens].
 
-        if self._prefill is not None:
-            logits, cache, cur = self._prefill(self.params, toks)
-            self._account(self.prefill_profile)
-            self.stats.prefill_tokens += B * Tp
-            next_tok = jnp.argmax(logits[:, -1, : self.cfg.vocab_size],
-                                  axis=-1).astype(jnp.int32)[:, None]
-            cur_index = jnp.int32(Tp)
-        else:
-            raise NotImplementedError("encdec serving uses serve_encdec()")
+        Host spans name each stage for the profiler: `serve.generate`
+        (stats `call`, this engine's call count from 1, and `batch`) holds
+        `serve.prefill`, then per decode step `serve.decode` and
+        `serve.sample` (stats `call` and `token`, the step from 0) with
+        `_account`'s `serve.account` between them, and last `serve.fetch`.
+        """
+        self._calls += 1
+        call = self._calls
+        with jax.profiler.TraceAnnotation("serve.generate", call=call,
+                                          batch=len(prompts)):
+            B, Tp = prompts.shape
+            assert B == self.batch_size, (B, self.batch_size)
+            if self._prefill is None:
+                raise NotImplementedError(
+                    "encdec serving uses serve_encdec()")
+            with jax.profiler.TraceAnnotation("serve.prefill"):
+                toks = jnp.asarray(prompts, jnp.int32)
+                logits, cache, cur = self._prefill(self.params, toks)
+                self._account(self.prefill_profile)
+                self.stats.prefill_tokens += B * Tp
+                next_tok = jnp.argmax(logits[:, -1, : self.cfg.vocab_size],
+                                      axis=-1).astype(jnp.int32)[:, None]
+                cur_index = jnp.int32(Tp)
 
-        out = [next_tok]
-        for i in range(max_new_tokens - 1):
-            if self.admission_gate and self._worst_chip_pinned():
-                self._defer_tick()
-            logits, cache = self._decode(
-                self.params, cache,
-                {"tokens": out[-1], "cur_index": cur_index})
-            self._account(self.decode_profile)
-            self.stats.decode_tokens += B
-            nxt = jnp.argmax(logits[:, -1, : self.cfg.vocab_size],
-                             axis=-1).astype(jnp.int32)[:, None]
-            out.append(nxt)
-            cur_index = cur_index + 1
-            if eos_id is not None and bool(jnp.all(nxt == eos_id)):
-                break
-        return np.asarray(jnp.concatenate(out, axis=1))
+            out = [next_tok]
+            for i in range(max_new_tokens - 1):
+                if self.admission_gate and self._worst_chip_pinned():
+                    self._defer_tick()
+                with jax.profiler.TraceAnnotation("serve.decode", call=call,
+                                                  token=i):
+                    logits, cache = self._decode(
+                        self.params, cache,
+                        {"tokens": out[-1], "cur_index": cur_index})
+                self._account(self.decode_profile)
+                with jax.profiler.TraceAnnotation("serve.sample", call=call,
+                                                  token=i):
+                    self.stats.decode_tokens += B
+                    nxt = jnp.argmax(logits[:, -1, : self.cfg.vocab_size],
+                                     axis=-1).astype(jnp.int32)[:, None]
+                    out.append(nxt)
+                    cur_index = cur_index + 1
+                    done = eos_id is not None and bool(jnp.all(nxt == eos_id))
+                if done:
+                    break
+            with jax.profiler.TraceAnnotation("serve.fetch"):
+                return np.asarray(jnp.concatenate(out, axis=1))
 
     def serve_trace(self, trace, *, max_ticks: int = 20_000,
                     observe=None, tick_s: "float | None" = None,

@@ -192,43 +192,59 @@ class Trainer:
         return self.log
 
     def _run_span(self, step: int) -> int:
+        """Steps until `total_steps`. Host spans name each stage for the
+        profiler: `train.step` (stat `step`) holds `train.batch`,
+        `train.dispatch`, `train.wait`, `train.control` (host-path
+        controller only), `train.telemetry` and `train.ckpt` (when a save
+        is due)."""
         cfg = self.cfg
+        span = jax.profiler.TraceAnnotation
         while step < cfg.total_steps:
-            batch = self.data.jax_batch(step)
-            t0 = time.perf_counter()
-            if "sor" in self.state:
-                # in-graph SOR step: the functional SorState rides the
-                # trainer state like any other carry (and checkpoints)
-                params, opt, plane, ef, sor_state, metrics = self.train_step(
-                    self.state["params"], self.state["opt"],
-                    self.state["plane"], self.state["ef"],
-                    self.state["sor"], batch)
-            else:
-                sor_state = None
-                params, opt, plane, ef, metrics = self.train_step(
-                    self.state["params"], self.state["opt"],
-                    self.state["plane"], self.state["ef"], batch)
-            jax.block_until_ready(metrics["loss"])
-            wall = time.perf_counter() - t0
-            wall = self._inject_faults(step, wall)
-            self._step_times.append(wall)
+            with span("train.step", step=step):
+                with span("train.batch"):
+                    batch = self.data.jax_batch(step)
+                t0 = time.perf_counter()
+                with span("train.dispatch"):
+                    if "sor" in self.state:
+                        # in-graph SOR step: the functional SorState rides
+                        # the trainer state like any other carry (and
+                        # checkpoints)
+                        params, opt, plane, ef, sor_state, metrics = \
+                            self.train_step(
+                                self.state["params"], self.state["opt"],
+                                self.state["plane"], self.state["ef"],
+                                self.state["sor"], batch)
+                    else:
+                        sor_state = None
+                        params, opt, plane, ef, metrics = self.train_step(
+                            self.state["params"], self.state["opt"],
+                            self.state["plane"], self.state["ef"], batch)
+                with span("train.wait"):
+                    jax.block_until_ready(metrics["loss"])
+                wall = time.perf_counter() - t0
+                wall = self._inject_faults(step, wall)
+                self._step_times.append(wall)
 
-            self.state.update(params=params, opt=opt, plane=plane, ef=ef)
-            if sor_state is not None:
-                self.state["sor"] = sor_state
+                self.state.update(params=params, opt=opt, plane=plane, ef=ef)
+                if sor_state is not None:
+                    self.state["sor"] = sor_state
 
-            # host-path control (SW analogue): one control_step through the
-            # unified rail control plane (decide + PMBus-actuate)
-            if cfg.controller is not None:
-                self.state["plane"] = cfg.controller.control_step(plane, metrics)
-                metrics = self._with_sor_metrics(metrics)
+                # host-path control (SW analogue): one control_step through
+                # the unified rail control plane (decide + PMBus-actuate)
+                if cfg.controller is not None:
+                    with span("train.control"):
+                        self.state["plane"] = cfg.controller.control_step(
+                            plane, metrics)
+                        metrics = self._with_sor_metrics(metrics)
 
-            self.log.append_from(step, metrics["loss"], metrics,
-                                 self.state["plane"])
-            step += 1
-            if cfg.ckpt_every and (step % cfg.ckpt_every == 0
-                                   or step == cfg.total_steps):
-                self._save(step)
+                with span("train.telemetry"):
+                    self.log.append_from(step, metrics["loss"], metrics,
+                                         self.state["plane"])
+                step += 1
+                if cfg.ckpt_every and (step % cfg.ckpt_every == 0
+                                       or step == cfg.total_steps):
+                    with span("train.ckpt"):
+                        self._save(step)
         return step
 
     def _with_sor_metrics(self, metrics: dict[str, Any]) -> dict[str, Any]:
