@@ -103,7 +103,7 @@ class ServeEngine:
             if not isinstance(self.controller, InGraphRailController):
                 raise ValueError("sor= needs an in-graph policy/controller "
                                  "(the serve loop threads SorState through "
-                                 "InGraphRailController.control_step_sor); "
+                                 "InGraphRailController.control_round); "
                                  "for a HostRailController pass sor= to the "
                                  "controller itself")
             # shared semantics with make_fleet_train_step (control_plane.
@@ -189,6 +189,16 @@ class ServeEngine:
             self._sharded_round = None
         self.shard_control = bool(shard_control)
         self._tick_cache: dict = {}   # (observe id, tick_s, bound) -> jit
+        # per-step accounting: an in-graph (or no) controller runs the
+        # accounting, the observation and its round as one `control_round`
+        # program per step; any other controller decides on the host from
+        # concrete telemetry, so it gets the accounting alone
+        self._fused = (self.controller is None
+                       or isinstance(self.controller, InGraphRailController))
+        self._step_cache: dict = {}   # StepProfile -> jitted step program
+        # (energy_step_j, t_step_s) device scalars of the accounted steps
+        # not yet added to `stats`
+        self._unpulled: list = []
 
         self._calls = 0              # generate() calls, the spans' `call`
 
@@ -208,8 +218,8 @@ class ServeEngine:
         return self.plane.n_chips
 
     def _control_tick(self, frame) -> None:
-        """One controller round on `frame` — shared by the per-step
-        accounting loop and the routed trace loop."""
+        """One controller round on `frame`, dispatched on its own: the
+        host control path's per-step round and the routed trace loop's."""
         if self.controller is None:
             return
         c = self.controller
@@ -228,24 +238,98 @@ class ServeEngine:
             else:
                 self.plane = c.control_step(self.plane, frame)
 
+    def _step_program(self, profile: StepProfile):
+        """The jitted per-step program for `profile`, built once.
+
+        Fused (in-graph or no controller): `control_round(plane, sor_state)
+        -> (plane', sor_state', request, envelope, energy_step_j,
+        t_step_s)` runs the accounting, the observation and the controller's
+        round (`InGraphRailController.control_round` with SOR, decide +
+        arbitrate without) as one program; the SorState is donated when
+        the controller donates. Otherwise `account(plane) -> (plane', frame,
+        energy_step_j, t_step_s)`, the accounting alone."""
+        fn = self._step_cache.get(profile)
+        if fn is not None:
+            return fn
+        spec, c = self.fleet_spec, self.controller
+
+        def observe(plane):
+            if spec is not None:
+                return account_fleet_and_observe(profile, plane, spec)
+            return account_and_observe(profile, plane)
+
+        if not self._fused:
+            def account(plane):
+                plane, frame, m = observe(plane)
+                return plane, frame, m["energy_step_j"], m["t_step_s"]
+
+            fn = jax.jit(account)
+        else:
+            use_sor = c is not None and c.sor is not None
+
+            def control_round(plane, sor_state):
+                plane, frame, m = observe(plane)
+                request = env = None
+                if use_sor:
+                    plane, sor_state, request, env = c.control_round(
+                        plane, frame, sor_state)
+                elif c is not None:
+                    plane, request = _run_policy(
+                        c.policy, plane, frame, frame, c.rail_map,
+                        host=False)
+                return (plane, sor_state, request, env, m["energy_step_j"],
+                        m["t_step_s"])
+
+            fn = jax.jit(control_round,
+                         donate_argnums=(1,) if use_sor and c.donate else ())
+        self._step_cache[profile] = fn
+        return fn
+
+    def _account_step(self, profile: StepProfile) -> None:
+        """Account one step and run the controller's round after it. The
+        step's energy and time stay on the device (`_unpulled`) until
+        `_pull`, except on the host path, whose round pulls the telemetry
+        anyway."""
+        c = self.controller
+        step = self._step_program(profile)
+        with jax.profiler.TraceAnnotation("serve.account"):
+            if self._fused:
+                if (c is not None and c.sor is not None
+                        and self._sor_state is None):
+                    self._sor_state = c.init_sor(
+                        self.n_chips if self.plane.is_fleet else None)
+                with jax.profiler.TraceAnnotation("serve.control"):
+                    (self.plane, self._sor_state, request, env, e,
+                     t) = step(self.plane, self._sor_state)
+                if c is not None:
+                    c.last_request, c.last_envelope = request, env
+                self._unpulled.append((e, t))
+            else:
+                self.plane, frame, e, t = step(self.plane)
+                self._unpulled.append((e, t))
+                self._pull()
+                self._control_tick(frame)
+
+    def _pull(self) -> None:
+        """Add the unpulled steps' energy and time to `stats`, in step
+        order, from one `device_get`: `serve.sync`, stat `steps` the number
+        of steps it carries. Array-aware reductions (TelemetryLog's
+        scalar-view convention): scalars pass through, [n_chips] metrics
+        report the fleet mean."""
+        if not self._unpulled:
+            return
+        steps, self._unpulled = self._unpulled, []
+        with jax.profiler.TraceAnnotation("serve.sync", steps=len(steps)):
+            for e, t in jax.device_get(steps):
+                e = scalar_view(e)
+                self.stats.energy_j += e
+                self.stats.fleet_energy_j += e * self.n_chips
+                self.stats.model_time_s += scalar_view(t)
+
     def _account(self, profile: StepProfile, n: int = 1):
         for _ in range(n):
-            with jax.profiler.TraceAnnotation("serve.account"):
-                if self.fleet_spec is not None:
-                    self.plane, frame, m = account_fleet_and_observe(
-                        profile, self.plane, self.fleet_spec)
-                else:
-                    self.plane, frame, m = account_and_observe(profile,
-                                                               self.plane)
-                # array-aware reductions (TelemetryLog's scalar-view
-                # convention): scalars pass through, [n_chips] metrics report
-                # the fleet mean
-                with jax.profiler.TraceAnnotation("serve.sync"):
-                    e = scalar_view(m["energy_step_j"])
-                    self.stats.energy_j += e
-                    self.stats.fleet_energy_j += e * self.n_chips
-                    self.stats.model_time_s += scalar_view(m["t_step_s"])
-                self._control_tick(frame)
+            self._account_step(profile)
+        self._pull()
 
     def _worst_chip_pinned(self) -> bool:
         """Did the latest arbitration pin any chip at any requested rail's
@@ -280,7 +364,7 @@ class ServeEngine:
                 self.stats.sheds_by_rail.get(rail, 0) + 1)
         self.stats.defer_time_s += scalar_view(
             step_time_s(self.decode_profile, self.plane))
-        self._account(self.decode_profile)
+        self._account_step(self.decode_profile)
 
     def generate(self, prompts: np.ndarray, max_new_tokens: int,
                  eos_id: int | None = None) -> np.ndarray:
@@ -290,7 +374,9 @@ class ServeEngine:
         (stats `call`, this engine's call count from 1, and `batch`) holds
         `serve.prefill`, then per decode step `serve.decode` and
         `serve.sample` (stats `call` and `token`, the step from 0) with
-        `_account`'s `serve.account` between them, and last `serve.fetch`.
+        `_account_step`'s `serve.account` between them, then `serve.fetch`
+        and last `serve.sync`, the one pull of the call's accounted energy
+        and time (per step instead on the host control path).
         """
         self._calls += 1
         call = self._calls
@@ -304,7 +390,7 @@ class ServeEngine:
             with jax.profiler.TraceAnnotation("serve.prefill"):
                 toks = jnp.asarray(prompts, jnp.int32)
                 logits, cache, cur = self._prefill(self.params, toks)
-                self._account(self.prefill_profile)
+                self._account_step(self.prefill_profile)
                 self.stats.prefill_tokens += B * Tp
                 next_tok = jnp.argmax(logits[:, -1, : self.cfg.vocab_size],
                                       axis=-1).astype(jnp.int32)[:, None]
@@ -319,7 +405,7 @@ class ServeEngine:
                     logits, cache = self._decode(
                         self.params, cache,
                         {"tokens": out[-1], "cur_index": cur_index})
-                self._account(self.decode_profile)
+                self._account_step(self.decode_profile)
                 with jax.profiler.TraceAnnotation("serve.sample", call=call,
                                                   token=i):
                     self.stats.decode_tokens += B
@@ -331,7 +417,9 @@ class ServeEngine:
                 if done:
                     break
             with jax.profiler.TraceAnnotation("serve.fetch"):
-                return np.asarray(jnp.concatenate(out, axis=1))
+                tokens = np.asarray(jnp.concatenate(out, axis=1))
+            self._pull()
+            return tokens
 
     def serve_trace(self, trace, *, max_ticks: int = 20_000,
                     observe=None, tick_s: "float | None" = None,

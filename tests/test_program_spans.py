@@ -110,27 +110,31 @@ def test_generate_opens_with_one_call_span_holding_the_prefill(served):
     assert gen.stats == {"call": 2, "batch": 2}
     top = _children(gen, spans, "serve.")
     assert top[0].name == "serve.prefill"
-    # the prefill's accounting carries the accounting's own spans
+    # the prefill's accounting dispatches its one program in
+    # `serve.control`; its sums are pulled at the end of the call
     acc = _children(top[0], spans, "serve.")
     assert [s.name for s in acc] == ["serve.account"]
     assert [s.name for s in _children(acc[0], spans, "serve.")] == \
-        ["serve.sync", "serve.control"]
+        ["serve.control"]
 
 
 def test_each_decode_step_has_its_spans_in_order(served):
     _, _, spans = served
     (gen,) = [s for s in spans if s.name == "serve.generate"]
     top = _children(gen, spans, "serve.")
-    steps = top[1:-1]
+    steps = top[1:-2]
     assert [s.name for s in steps] == \
         ["serve.decode", "serve.account", "serve.sample"] * (NEW - 1)
-    assert top[-1].name == "serve.fetch"
+    # one pull of the whole call's accounting, once the tokens are fetched:
+    # the prefill's step and each decode step's
+    assert [s.name for s in top[-2:]] == ["serve.fetch", "serve.sync"]
+    assert top[-1].stats == {"steps": NEW}
     for i in range(NEW - 1):
         dec, acc, smp = steps[3 * i:3 * i + 3]
         assert dec.stats == smp.stats == {"call": 2, "token": i}
         assert acc.stats == {}
         assert [s.name for s in _children(acc, spans, "serve.")] == \
-            ["serve.sync", "serve.control"]
+            ["serve.control"]
 
 
 def test_tokens_do_not_change_under_the_profiler(served):
