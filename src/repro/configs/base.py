@@ -17,7 +17,7 @@ VOCAB_ALIGN = 2048  # pad vocab to a multiple (TP-16 x 128-lane friendly)
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                 # dense | moe | hybrid | ssm | encdec | vlm
+    family: str       # dense | moe | mla_moe | hybrid | ssm | encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -31,6 +31,20 @@ class ModelConfig:
     # MoE
     n_experts: int = 0
     experts_per_token: int = 0
+    # mla_moe (DeepSeek-V3 block): latent attention widths, leading dense
+    # layers, expert width beside the dense width (d_ff), shared experts,
+    # sigmoid routing with a selection-only correction bias, and the range
+    # of routed experts this chip holds under expert parallelism
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    n_dense_layers: int = 0
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    first_held_expert: int = 0
+    n_held_experts: int = 0
     # SSM / hybrid
     ssm_state: int = 0
     attn_every: int = 0         # hybrid: shared attn block after every k SSM blocks
@@ -131,7 +145,7 @@ SHAPES: dict[str, ShapeConfig] = {
 ARCH_IDS = (
     "zamba2_1p2b", "minicpm_2b", "granite_20b", "mistral_large_123b",
     "qwen2p5_14b", "rwkv6_7b", "internvl2_2b", "whisper_base",
-    "grok1_314b", "qwen3_moe_30b_a3b",
+    "grok1_314b", "qwen3_moe_30b_a3b", "moonlight_16b_a3b",
 )
 
 # long_500k runs only for sub-quadratic archs (DESIGN.md §4); whisper has a

@@ -75,6 +75,23 @@ def decode_attention(q, k, v, lengths, *, group: int = 1):
                              lengths=lengths)
 
 
+@functools.partial(jax.jit, static_argnames=("scale",))
+def mla_decode_attention(q_lat, q_rope, c_kv, k_rope, layer, lengths, *,
+                         scale: float):
+    """Latent-attention decode: q_lat [B,H,R], q_rope [B,H,Dr] against layer
+    `layer` [] of the stacked latent cache c_kv [L,B,S,R], k_rope
+    [L,B,S,Dr]; lengths [B] valid slots -> [B,H,R]
+    (kernels/mla_decode_attention.py)."""
+    mode = _pallas_mode()
+    if mode != "off":
+        from repro.kernels import mla_decode_attention as mla
+        return mla.mla_decode_attention(q_lat, q_rope, c_kv, k_rope, layer,
+                                        lengths, scale=scale,
+                                        interpret=(mode == "interpret"))
+    return ref.mla_decode_reference(q_lat, q_rope, c_kv, k_rope, layer,
+                                    lengths, scale=scale)
+
+
 # ---------------------------------------------------------------------------
 # Mamba2 SSD chunked scan
 #

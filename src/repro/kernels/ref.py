@@ -47,6 +47,23 @@ def mha_reference(q, k, v, *, causal: bool = True, group: int = 1,
     return out.astype(q.dtype)
 
 
+def mla_decode_reference(q_lat, q_rope, c_kv, k_rope, layer, lengths, *,
+                         scale):
+    """Latent-attention decode (absorbed form): q_lat [B,H,R] and q_rope
+    [B,H,Dr] against layer `layer` of the stacked latent cache c_kv
+    [L,B,S,R] and rope keys k_rope [L,B,S,Dr], shared by the heads; slots
+    >= lengths [B] masked; c_kv is the value. Accumulates in f32, returns
+    q_lat.dtype [B,H,R]."""
+    c_kv, k_rope = c_kv[layer], k_rope[layer]
+    f = lambda a: a.astype(jnp.float32)
+    s = (jnp.einsum("bhr,bsr->bhs", f(q_lat), f(c_kv))
+         + jnp.einsum("bhd,bsd->bhs", f(q_rope), f(k_rope))) * scale
+    valid = jnp.arange(c_kv.shape[1])[None, :] < lengths[:, None]
+    s = jnp.where(valid[:, None, :], s, jnp.float32(-1e30))
+    out = jnp.einsum("bhs,bsr->bhr", jax.nn.softmax(s, axis=-1), f(c_kv))
+    return out.astype(q_lat.dtype)
+
+
 # ---------------------------------------------------------------------------
 # Mamba2 SSD oracle (sequential scan over time)
 # ---------------------------------------------------------------------------

@@ -44,6 +44,9 @@ MICROBATCHES = {
     "qwen2p5_14b": 4, "qwen3_moe_30b_a3b": 2, "rwkv6_7b": 4,
     "zamba2_1p2b": 2, "minicpm_2b": 2, "internvl2_2b": 2, "whisper_base": 1,
 }
+# configurations stated as one chip's share of a deployment, not sized for
+# the production meshes: the dry run leaves them out
+CHIP_SHARE_ONLY = ("moonlight_16b_a3b",)
 # >=100B-param models use int8 optimizer moments (DESIGN.md §5)
 INT8_OPT = {"mistral_large_123b", "grok1_314b"}
 
@@ -343,7 +346,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=ARCH_IDS)
+    ap.add_argument("--arch", choices=[a for a in ARCH_IDS
+                                       if a not in CHIP_SHARE_ONLY])
     ap.add_argument("--shape", choices=list(SHAPES))
     ap.add_argument("--mesh", choices=("single", "multi", "both"),
                     default="single")
@@ -353,7 +357,8 @@ def main():
     args = ap.parse_args()
 
     if args.all:
-        todo = [(a, s) for a, s, runnable in cells() if runnable]
+        todo = [(a, s) for a, s, runnable in cells()
+                if runnable and a not in CHIP_SHARE_ONLY]
     else:
         if not (args.arch and args.shape):
             ap.error("--arch/--shape or --all required")

@@ -1,5 +1,5 @@
-"""Decoder-only LM assembly covering the dense / moe / hybrid (zamba2) /
-ssm (rwkv6) / vlm families.
+"""Decoder-only LM assembly covering the dense / moe / mla_moe
+(DeepSeek-V3 block) / hybrid (zamba2) / ssm (rwkv6) / vlm families.
 
 Layer stack runs as a two-level lax.scan over stacked parameters
 (groups x layers-per-group) with configurable activation checkpointing:
@@ -9,6 +9,12 @@ mistral-large-123b train_4k fit (DESIGN.md §5).
 
 Decode paths carry per-layer caches stacked on a leading layer axis and
 advance them through the same scan machinery (no remat).
+
+The mla_moe family keeps two stacks: its leading dense layers
+(`dense_blocks`) run before the scan over its MoE layers (`blocks`), each
+with a latent cache (models/mla.py); its decode step also sums, over its
+MoE layers, the (token, held expert) pairs computed and the held experts
+hit, into the cache's `stats`.
 """
 
 from __future__ import annotations
@@ -22,14 +28,17 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
-from repro.models import common, mamba2, mlp, rwkv6
+from repro.models import common, mamba2, mla, mlp, rwkv6
 from repro.models.attention import AttnSpec
 from repro.models.mamba2 import Mamba2Spec
-from repro.models.mlp import MoESpec
+from repro.models.mla import MlaSpec
+from repro.models.mlp import HeldMoESpec, MoESpec
 from repro.models.rwkv6 import Rwkv6Spec
 from repro.parallel.sharding import constrain
 
 MOE_AUX_COEF = 0.01
+PREFILL_TOKENS = 16384   # mla_moe prefill: tokens a pass over the layers
+MOE_STATS = ("moe_pairs_held", "moe_experts_hit")
 
 
 # ---------------------------------------------------------------------------
@@ -46,6 +55,22 @@ def attn_spec(cfg: ModelConfig, *, causal=True, sliding=False) -> AttnSpec:
 def moe_spec(cfg: ModelConfig) -> MoESpec:
     return MoESpec(d_model=cfg.d_model, d_ff=cfg.d_ff,
                    n_experts=cfg.n_experts, k=cfg.experts_per_token)
+
+
+def mla_spec(cfg: ModelConfig) -> MlaSpec:
+    return MlaSpec(
+        d_model=cfg.d_model, n_heads=cfg.n_heads,
+        kv_lora_rank=cfg.kv_lora_rank, qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+        rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps)
+
+
+def held_moe_spec(cfg: ModelConfig) -> HeldMoESpec:
+    return HeldMoESpec(
+        d_model=cfg.d_model, d_ff=cfg.moe_d_ff, n_experts=cfg.n_experts,
+        k=cfg.experts_per_token, first_held=cfg.first_held_expert,
+        n_held=cfg.n_held_experts, n_shared=cfg.n_shared_experts,
+        scaling=cfg.routed_scaling_factor)
 
 
 def mamba_spec(cfg: ModelConfig) -> Mamba2Spec:
@@ -84,6 +109,16 @@ def _init_block(key, cfg: ModelConfig, dtype):
     raise ValueError(f"family {cfg.family} not handled by lm.py")
 
 
+def _init_mla_block(key, cfg: ModelConfig, dtype, moe: bool):
+    k1, k2 = jax.random.split(key)
+    ffn = (("moe", mlp.init_held_moe(k2, held_moe_spec(cfg), dtype)) if moe
+           else ("mlp", mlp.init_swiglu(k2, cfg.d_model, cfg.d_ff, dtype)))
+    return {"ln1_w": jnp.ones((cfg.d_model,), dtype),
+            "attn": mla.init_mla(k1, mla_spec(cfg), dtype),
+            "ln2_w": jnp.ones((cfg.d_model,), dtype),
+            ffn[0]: ffn[1]}
+
+
 def init_lm(key, cfg: ModelConfig):
     dtype = common.default_dtype(cfg.dtype)
     keys = jax.random.split(key, cfg.n_layers + 4)
@@ -92,9 +127,18 @@ def init_lm(key, cfg: ModelConfig):
         "embed": common.embed_init(keys[0], (Vp, D), dtype),
         "final_norm_w": jnp.ones((D,), dtype),
         "lm_head": common.dense_init(keys[1], (D, Vp), D, dtype),
-        "blocks": jax.vmap(lambda k: _init_block(k, cfg, dtype))(
-            jnp.stack(keys[4:4 + cfg.n_layers])),
     }
+    if cfg.family == "mla_moe":
+        nd = cfg.n_dense_layers
+        params["dense_blocks"] = jax.vmap(
+            lambda k: _init_mla_block(k, cfg, dtype, moe=False))(
+            jnp.stack(keys[4:4 + nd]))
+        params["blocks"] = jax.vmap(
+            lambda k: _init_mla_block(k, cfg, dtype, moe=True))(
+            jnp.stack(keys[4 + nd:4 + cfg.n_layers]))
+        return params
+    params["blocks"] = jax.vmap(lambda k: _init_block(k, cfg, dtype))(
+        jnp.stack(keys[4:4 + cfg.n_layers]))
     if cfg.family == "hybrid":
         # zamba2: one *shared* attention+mlp block reused every attn_every
         # mamba layers (arXiv:2411.15242)
@@ -184,6 +228,40 @@ def _run_blocks(params, x, cfg: ModelConfig, positions, *, remat: str = "full"):
     return x, aux
 
 
+def _mla_block(cfg: ModelConfig, p, x, attend):
+    """One mla_moe layer: RMSNorm, `attend(attn params, h) -> (a, cache)`,
+    residual, RMSNorm, the dense SwiGLU or the held-expert MoE, residual.
+    Returns (x, cache, counts or None)."""
+    h = common.rms_norm(x, p["ln1_w"], cfg.norm_eps)
+    a, c = attend(p["attn"], h)
+    x = x + a
+    h = common.rms_norm(x, p["ln2_w"], cfg.norm_eps)
+    if "moe" in p:
+        m, counts = mlp.held_moe_apply(p["moe"], h, held_moe_spec(cfg))
+    else:
+        m, counts = mlp.swiglu(p["mlp"], h), None
+    return x + m, c, counts
+
+
+def _run_mla(params, x, cfg: ModelConfig, positions, *, remat: str = "none",
+             collect: bool = False):
+    """The dense layers, then the MoE layers, each stack one scan. Returns
+    (x, (dense latents, moe latents)), each (c_kv [L,B,T,R], k_rope
+    [L,B,T,Dr]) when `collect`, else None."""
+    spec = mla_spec(cfg)
+
+    def body(x, p):
+        x, kv, _ = _mla_block(
+            cfg, p, x, lambda ap, h: mla.mla_full(ap, h, spec, positions))
+        return x, kv if collect else None
+
+    if remat != "none":
+        body = jax.checkpoint(body)
+    x, dense_kv = jax.lax.scan(body, x, params["dense_blocks"])
+    x, moe_kv = jax.lax.scan(body, x, params["blocks"])
+    return x, (dense_kv, moe_kv)
+
+
 # ---------------------------------------------------------------------------
 # Full forward + loss
 # ---------------------------------------------------------------------------
@@ -219,7 +297,11 @@ def forward_train(params, batch, cfg: ModelConfig, *, remat: str = "full"):
             [jnp.full(img.shape[:2], -1, labels.dtype), labels], axis=1)
     B, T = x.shape[0], x.shape[1]
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
-    x, aux = _run_blocks(params, x, cfg, positions, remat=remat)
+    if cfg.family == "mla_moe":
+        x, _ = _run_mla(params, x, cfg, positions, remat=remat)
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        x, aux = _run_blocks(params, x, cfg, positions, remat=remat)
     logits = logits_from(params, x, cfg)
     loss = common.softmax_cross_entropy(logits, labels)
     total = loss + MOE_AUX_COEF * aux / max(cfg.n_layers, 1)
@@ -233,7 +315,10 @@ def forward_logits(params, tokens, cfg: ModelConfig):
     x = embed_tokens(params, tokens, cfg)
     B, T = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))
-    x, _ = _run_blocks(params, x, cfg, positions, remat="none")
+    if cfg.family == "mla_moe":
+        x, _ = _run_mla(params, x, cfg, positions)
+    else:
+        x, _ = _run_blocks(params, x, cfg, positions, remat="none")
     return logits_from(params, x, cfg)
 
 
@@ -246,12 +331,17 @@ def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int):
     dtype = common.default_dtype(cfg.dtype)
     L = cfg.n_layers
 
-    def stack(tree):
+    def stack(tree, n=L):
         return jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a[None], (L,) + a.shape).copy(), tree)
+            lambda a: jnp.broadcast_to(a[None], (n,) + a.shape).copy(), tree)
 
     if cfg.family in ("dense", "vlm", "moe"):
         return stack(attn.init_kv_cache(batch, max_len, attn_spec(cfg), dtype))
+    if cfg.family == "mla_moe":
+        c = mla.init_latent_cache(batch, max_len, mla_spec(cfg), dtype)
+        nd = cfg.n_dense_layers
+        return {"dense": stack(c, nd), "moe": stack(c, L - nd),
+                "stats": {k: jnp.zeros((), jnp.int32) for k in MOE_STATS}}
     if cfg.family == "hybrid":
         n_occ = cfg.n_layers // cfg.attn_every
         mamba_state = mamba2.init_mamba2_state(batch, mamba_spec(cfg), dtype)
@@ -267,8 +357,70 @@ def init_decode_cache(cfg: ModelConfig, batch: int, max_len: int):
     raise ValueError(cfg.family)
 
 
+def _mla_decode_step(params, cache, tokens, cur_index, cfg: ModelConfig):
+    """The latent caches ride each stack's layer scan whole, in its carry:
+    each layer writes its new position in place and its kernel reads its
+    layer of the stack where it lies (models/mla.py)."""
+    spec = mla_spec(cfg)
+
+    def run(x, blocks, stack, sums):
+        def body(carry, xs):
+            x, c, sums = carry
+            p, i = xs
+            x, c, counts = _mla_block(
+                cfg, p, x,
+                lambda ap, h: mla.mla_decode(ap, h, c, i, cur_index, spec))
+            if counts is not None:
+                sums = {k: sums[k] + counts[k] for k in MOE_STATS}
+            return (x, c, sums), None
+
+        n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+        (x, stack, sums), _ = jax.lax.scan(
+            body, (x, stack, sums), (blocks, jnp.arange(n, dtype=jnp.int32)))
+        return x, stack, sums
+
+    x = embed_tokens(params, tokens, cfg)
+    x, dense_c, stats = run(x, params["dense_blocks"], cache["dense"],
+                            cache["stats"])
+    x, moe_c, stats = run(x, params["blocks"], cache["moe"], stats)
+    return logits_from(params, x, cfg), {"dense": dense_c, "moe": moe_c,
+                                         "stats": stats}
+
+
+def _mla_prefill(params, tokens, cfg: ModelConfig, max_len: int):
+    """Prefill of the mla_moe family in passes of whole rows, at most
+    PREFILL_TOKENS tokens a pass (one row at least), each pass writing its
+    rows' latents into the cache: the activations of the 4k-token rows of a
+    serving batch would not fit beside the cache in one pass."""
+    B, T = tokens.shape
+    rows = max(1, min(B, PREFILL_TOKENS // T))
+    while B % rows:
+        rows -= 1
+    dtype = common.default_dtype(cfg.dtype)
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None],
+                                 (rows, T))
+
+    def one_pass(cache, i):
+        toks = jax.lax.dynamic_slice_in_dim(tokens, i * rows, rows)
+        x = embed_tokens(params, toks, cfg)
+        x, kvs = _run_mla(params, x, cfg, positions, collect=True)
+        for part, kv in zip(("dense", "moe"), kvs):
+            cache[part] = {
+                name: jax.lax.dynamic_update_slice(
+                    cache[part][name], a.astype(dtype), (0, i * rows, 0, 0))
+                for name, a in zip(("c_kv", "k_rope"), kv)}
+        return cache, logits_from(params, x[:, -1:], cfg)
+
+    cache, logits = jax.lax.scan(one_pass,
+                                 init_decode_cache(cfg, B, max_len),
+                                 jnp.arange(B // rows))
+    return logits.reshape((B,) + logits.shape[2:]), cache, T
+
+
 def decode_step(params, cache, tokens, cur_index, cfg: ModelConfig):
     """One serving step: tokens [B,1] -> (logits [B,1,V], new_cache)."""
+    if cfg.family == "mla_moe":
+        return _mla_decode_step(params, cache, tokens, cur_index, cfg)
     x = embed_tokens(params, tokens, cfg)
     B = x.shape[0]
     shared = params.get("shared")
@@ -345,6 +497,8 @@ def decode_step(params, cache, tokens, cur_index, cfg: ModelConfig):
 def prefill(params, tokens, cfg: ModelConfig, max_len: int):
     """Prefill pass: run the full prompt, return (last_logits, cache, T).
     Uses the train forward plus per-layer cache collection (no remat)."""
+    if cfg.family == "mla_moe":
+        return _mla_prefill(params, tokens, cfg, max_len)
     B, T = tokens.shape
     x = embed_tokens(params, tokens, cfg)
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None], (B, T))

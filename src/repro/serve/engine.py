@@ -63,6 +63,10 @@ class ServeStats:
     # for back-compat; these are their per-rail / per-reason split)
     sheds_by_rail: dict = dataclasses.field(default_factory=dict)
     sheds_by_reason: dict = dataclasses.field(default_factory=dict)
+    # held-expert MoE decode steps (mla_moe family): (token, held expert)
+    # pairs computed and held experts hit, summed over layers and steps
+    moe_pairs_held: int = 0
+    moe_experts_hit: int = 0
 
 
 class ServeEngine:
@@ -310,21 +314,30 @@ class ServeEngine:
                 self._pull()
                 self._control_tick(frame)
 
-    def _pull(self) -> None:
+    def _pull(self, counters: "dict | None" = None) -> None:
         """Add the unpulled steps' energy and time to `stats`, in step
-        order, from one `device_get`: `serve.sync`, stat `steps` the number
-        of steps it carries. Array-aware reductions (TelemetryLog's
-        scalar-view convention): scalars pass through, [n_chips] metrics
-        report the fleet mean."""
-        if not self._unpulled:
+        order, and the model's decode `counters` (device scalars summed over
+        a call's steps), from one `device_get`: `serve.sync`, stat `steps`
+        the number of steps it carries, and one stat per counter, its
+        value. Array-aware reductions (TelemetryLog's scalar-view
+        convention): scalars pass through, [n_chips] metrics report the
+        fleet mean."""
+        if not self._unpulled and not counters:
             return
         steps, self._unpulled = self._unpulled, []
-        with jax.profiler.TraceAnnotation("serve.sync", steps=len(steps)):
-            for e, t in jax.device_get(steps):
+        with jax.profiler.TraceAnnotation("serve.sync",
+                                          steps=len(steps)) as span:
+            steps, counters = jax.device_get((steps, counters))
+            for e, t in steps:
                 e = scalar_view(e)
                 self.stats.energy_j += e
                 self.stats.fleet_energy_j += e * self.n_chips
                 self.stats.model_time_s += scalar_view(t)
+            if counters:
+                counters = {k: int(v) for k, v in counters.items()}
+                for k, v in counters.items():
+                    setattr(self.stats, k, getattr(self.stats, k) + v)
+                span.set_metadata(**counters)
 
     def _account(self, profile: StepProfile, n: int = 1):
         for _ in range(n):
@@ -376,7 +389,9 @@ class ServeEngine:
         `serve.sample` (stats `call` and `token`, the step from 0) with
         `_account_step`'s `serve.account` between them, then `serve.fetch`
         and last `serve.sync`, the one pull of the call's accounted energy
-        and time (per step instead on the host control path).
+        and time (per step instead on the host control path) and of the
+        decode counters the model's cache carries (`stats`: the mla_moe
+        family's held-expert sums).
         """
         self._calls += 1
         call = self._calls
@@ -418,7 +433,8 @@ class ServeEngine:
                     break
             with jax.profiler.TraceAnnotation("serve.fetch"):
                 tokens = np.asarray(jnp.concatenate(out, axis=1))
-            self._pull()
+            self._pull(cache.get("stats") if isinstance(cache, dict)
+                       else None)
             return tokens
 
     def serve_trace(self, trace, *, max_ticks: int = 20_000,

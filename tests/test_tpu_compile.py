@@ -89,6 +89,29 @@ def test_decode_attention_minicpm_cache(one_chip, S):
         ((4, S, hkv, dh), BF16), ((4,), jnp.int32)) == {"decode_attention"}
 
 
+def test_mla_decode_attention_moonlight_cache(one_chip):
+    """16 heads of a row against its 4,352-slot latent cache (512 + 64),
+    one layer of the 26 MoE layers' stack."""
+    from repro.kernels import mla_decode_attention as mla
+    cfg = get_config("moonlight_16b_a3b")
+    H, R, Dr, S = (cfg.n_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim,
+                   4352)
+    assert _kernels(
+        lambda ql, qr, c, kr, i, n: mla.mla_decode_attention(
+            ql, qr, c, kr, i, n, scale=192 ** -0.5),
+        one_chip, ((16, H, R), BF16), ((16, H, Dr), BF16),
+        ((26, 16, S, R), BF16), ((26, 16, S, Dr), BF16), ((), jnp.int32),
+        ((16,), jnp.int32)) == {"mla_decode_attention"}
+
+
+def test_flash_attention_fwd_moonlight_prefill(one_chip):
+    """The latent-attention prefill: 16 heads of width 192 (V padded)."""
+    from repro.kernels import flash_attention as fa
+    x = ((1, SEQ, 16, 192), BF16)
+    assert _kernels(lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+                    one_chip, x, x, x) == {"flash_attention_fwd"}
+
+
 def test_mamba2_ssd_zamba2(one_chip):
     from repro.kernels import mamba2_ssd as m2
     spec = mamba_spec(get_config("zamba2_1p2b"))
