@@ -14,8 +14,8 @@ lives in VMEM scratch. dt travels as a lane-major row per chunk.
 
 Backward: the op is exposed through jax.custom_vjp in ops.py with the
 differentiable chunked jnp reference (ref.mamba2_chunked_reference) as the
-bwd path — fwd runs the kernel, bwd recomputes via XLA. Exact (same math),
-documented perf trade-off in DESIGN.md §6.
+bwd path — fwd runs the kernel, bwd recomputes the same chunked matmuls in
+f32 through XLA and differentiates them.
 """
 
 from __future__ import annotations

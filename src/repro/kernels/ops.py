@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.tiles import pad_to, seq_tile
 
 
 def _pallas_mode() -> str:
@@ -95,9 +94,10 @@ def mla_decode_attention(q_lat, q_rope, c_kv, k_rope, layer, lengths, *,
 # ---------------------------------------------------------------------------
 # Mamba2 SSD chunked scan
 #
-# The Pallas scans run the forward; the backward recomputes through the
-# differentiable jnp reference (identical math) via custom_vjp, so training
-# through the kernels is exact on TPU. Off-TPU the reference runs directly.
+# The Pallas kernel runs the forward. Its custom_vjp backward differentiates
+# ref.mamba2_chunked_reference, the same SSD chunked math as jnp matmuls
+# over the op's own `chunk` (no per-step loop), recomputed from the inputs.
+# Off-TPU the sequential reference runs directly: the tests' oracle.
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
@@ -112,36 +112,11 @@ def _mamba2_fwd(x, dt, A, B, C, D, chunk, interpret, init_state):
     return out, (x, dt, A, B, C, D, init_state)
 
 
-def _mamba2_remat_reference(x, dt, A, B, C, D, init_state, *, chunk):
-    """`ref.mamba2_scan_reference` run over `chunk`-step pieces, each under
-    `jax.checkpoint`: its vjp keeps one state per piece and recomputes a
-    piece's per-step states while differentiating it, where the plain
-    sequential scan keeps all T of them ([T, B, H, N, P] f32 — 4 GiB per
-    layer at zamba2_1p2b's widths for 4 x 512 tokens). Padded steps have
-    dt = 0, which leaves the state as it was."""
-    T = x.shape[1]
-    c, t_pad = seq_tile(T, chunk)
-    n = t_pad // c
-
-    def pieces(a):
-        a = pad_to(a, 1, t_pad)
-        return jnp.moveaxis(a.reshape(a.shape[0], n, c, *a.shape[2:]), 1, 0)
-
-    @jax.checkpoint
-    def piece(s, xs):
-        xc, dtc, bc, cc = xs
-        y, s = ref.mamba2_scan_reference(xc, dtc, A, bc, cc, D, init_state=s)
-        return s, y
-
-    s, ys = jax.lax.scan(piece, init_state, tuple(map(pieces, (x, dt, B, C))))
-    y = jnp.moveaxis(ys, 0, 1).reshape(x.shape[0], t_pad, *x.shape[2:])
-    return y[:, :T], s
-
-
 def _mamba2_bwd(chunk, interpret, res, g):
     x, dt, A, B, C, D, init_state = res
     _, vjp = jax.vjp(
-        lambda *a: _mamba2_remat_reference(*a, chunk=chunk),
+        lambda *a: ref.mamba2_chunked_reference(*a[:6], chunk=chunk,
+                                                init_state=a[6]),
         x, dt, A, B, C, D,
         init_state if init_state is not None
         else jnp.zeros((x.shape[0], x.shape[2], B.shape[3], x.shape[3]),

@@ -6,8 +6,12 @@ assert_allclose against these, and non-TPU backends execute them directly
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.tiles import pad_to, seq_tile
 
 
 # ---------------------------------------------------------------------------
@@ -65,7 +69,7 @@ def mla_decode_reference(q_lat, q_rope, c_kv, k_rope, layer, lengths, *,
 
 
 # ---------------------------------------------------------------------------
-# Mamba2 SSD oracle (sequential scan over time)
+# Mamba2 SSD oracle (sequential scan over time) and its chunked SSD form
 # ---------------------------------------------------------------------------
 
 def mamba2_scan_reference(x, dt, A, B, C, D, *, init_state=None):
@@ -113,6 +117,67 @@ def mamba2_scan_reference(x, dt, A, B, C, D, *, init_state=None):
     s_fin, ys = jax.lax.scan(step, s0, xs)
     y = jnp.moveaxis(ys, 0, 1) + Df[None, None, :, None] * xf
     return y.astype(x.dtype), s_fin
+
+
+def _decay(a):
+    """exp(a_i - a_j) for j <= i, else 0, over the last axis of a
+    decreasing cumsum `a` [..., L] -> [..., L, L]. Every kept exponent is
+    <= 0; the rest are masked before the exp, so that neither the value
+    nor its gradient meets an overflow."""
+    L = a.shape[-1]
+    causal = jnp.tril(jnp.ones((L, L), bool))
+    return jnp.exp(jnp.where(causal, a[..., :, None] - a[..., None, :],
+                             -jnp.inf))
+
+
+def mamba2_chunked_reference(x, dt, A, B, C, D, *, chunk, init_state=None):
+    """`mamba2_scan_reference` in the SSD (state-space dual) form, as
+    matmuls over chunks of `chunk` steps, with no per-step loop: the
+    differentiable form the kernel's backward runs. Same arguments and
+    returns. With a = cumsum(dt * A) inside a chunk of length L:
+
+      within chunk:  y_i  = sum_{j<=i} (C_i . B_j) e^{a_i - a_j} dt_j x_j
+      cross chunk:   y_i += (C_i e^{a_i}) . S_prev
+      carry:         S    = e^{a_L} S_prev + sum_j e^{a_L - a_j} dt_j B_j x_j^T
+
+    The carry is one more masked-decay matmul over the chunk axis. All in
+    f32 at HIGHEST precision. B and C stay per group (C . B^T is shared by
+    a group's heads). A length that does not tile is zero-padded: padded
+    steps (dt = 0) leave the state as it was."""
+    Bt, T, H, P = x.shape
+    G, N = B.shape[2], B.shape[3]
+    R = H // G                                  # heads per group
+    c, t_pad = seq_tile(T, chunk)
+    n = t_pad // c
+    mm = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+
+    def chunks(v, *tail):
+        return pad_to(v.astype(jnp.float32), 1, t_pad).reshape(Bt, n, c, *tail)
+
+    xc, Bc, Cc = chunks(x, G, R, P), chunks(B, G, N), chunks(C, G, N)
+    dtc = jnp.moveaxis(chunks(dt, G, R), 2, -1)            # [Bt,n,G,R,c]
+    a = jnp.cumsum(dtc * A.astype(jnp.float32).reshape(G, R, 1), axis=-1)
+    a_last = a[..., -1]                                    # [Bt,n,G,R]
+
+    scores = mm("bkign,bkjgn->bkgij", Cc, Bc)              # per group
+    w = scores[:, :, :, None] * _decay(a) * dtc[..., None, :]
+    y = mm("bkgrij,bkjgrp->bkigrp", w, xc)
+
+    # each chunk's own state, then the state entering each chunk (and the
+    # final one) as a decay-weighted sum over the chunks before it
+    w = jnp.moveaxis(jnp.exp(a_last[..., None] - a) * dtc, -1, 2)
+    s_chunk = mm("bkjgn,bkjgrp->bkgrnp", Bc, w[..., None] * xc)
+    s0 = (jnp.zeros((Bt, G, R, N, P), jnp.float32) if init_state is None
+          else init_state.astype(jnp.float32).reshape(Bt, G, R, N, P))
+    a_in = jnp.cumsum(jnp.pad(a_last, ((0, 0), (1, 0), (0, 0), (0, 0))),
+                      axis=1)                              # [Bt,n+1,G,R]
+    s = mm("bgrkm,bmgrnp->bkgrnp", _decay(jnp.moveaxis(a_in, 1, -1)),
+           jnp.concatenate([s0[:, None], s_chunk], axis=1))
+
+    y = y + (mm("bkign,bkgrnp->bkigrp", Cc, s[:, :n])
+             * jnp.moveaxis(jnp.exp(a), -1, 2)[..., None])
+    y = y.reshape(Bt, t_pad, H, P)[:, :T] + D.astype(jnp.float32)[:, None] * x
+    return y.astype(x.dtype), s[:, n].reshape(Bt, H, N, P)
 
 
 # ---------------------------------------------------------------------------

@@ -111,22 +111,65 @@ def test_mamba2_ssd_sweep(T, H, P, G, N, chunk):
                                rtol=1e-3, atol=2e-4)
 
 
-@pytest.mark.parametrize("with_state", [False, True])
-def test_mamba2_kernel_grads_match_reference(with_state):
-    """The kernel's custom vjp (the reference differentiated piece by piece
-    under remat) gives the sequential reference's gradients, at a length
-    that does not tile by the chunk."""
-    from repro.kernels import ops
-    ks = jax.random.split(KEY, 8)
-    Bt, T, H, P, G, N = 1, 100, 2, 16, 1, 16
+def _mamba2_inputs(Bt, T, H, P, G, N, *, with_state=False, decay=1.0):
+    """Random scan inputs; `decay` scales dt."""
+    ks = jax.random.split(KEY, 7)
     x = jax.random.normal(ks[0], (Bt, T, H, P))
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (Bt, T, H)))
+    dt = decay * jax.nn.softplus(jax.random.normal(ks[1], (Bt, T, H)))
     A = -jnp.exp(jax.random.normal(ks[2], (H,)))
     Bm = jax.random.normal(ks[3], (Bt, T, G, N))
     Cm = jax.random.normal(ks[4], (Bt, T, G, N))
     D = jax.random.normal(ks[5], (H,))
-    s0 = (jax.random.normal(ks[6], (Bt, H, N, P)) if with_state else None)
-    gy = jax.random.normal(ks[7], (Bt, T, H, P))
+    s0 = jax.random.normal(ks[6], (Bt, H, N, P)) if with_state else None
+    return (x, dt, A, Bm, Cm, D), s0
+
+
+@pytest.mark.parametrize("chunk", [32, 64])
+@pytest.mark.parametrize("T", [128, 100])
+@pytest.mark.parametrize("G", [1, 2])
+@pytest.mark.parametrize("with_state", [False, True])
+def test_mamba2_chunked_reference_matches_scan(chunk, T, G, with_state):
+    """The chunked (SSD dual) form equals the sequential scan in y and the
+    final state, at lengths that tile by the chunk and do not."""
+    args, s0 = _mamba2_inputs(2, T, 4, 16, G, 16, with_state=with_state)
+    y1, s1 = ref.mamba2_chunked_reference(*args, chunk=chunk, init_state=s0)
+    y2, s2 = ref.mamba2_scan_reference(*args, init_state=s0)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_mamba2_chunked_reference_strong_decay_grads_finite():
+    """|A| dt summing to hundreds within a chunk underflows the decays to
+    0; the masked segment sum keeps every gradient finite."""
+    args, s0 = _mamba2_inputs(1, 100, 2, 16, 1, 16, with_state=True,
+                              decay=10.0)
+    assert float(jnp.min(jnp.sum(-args[1][:, :64] * args[2], axis=1))) > 200
+
+    def loss(*a):
+        y, s = ref.mamba2_chunked_reference(*a[:6], chunk=64, init_state=a[6])
+        return jnp.sum(y) + jnp.sum(s)
+
+    grads = jax.grad(loss, argnums=tuple(range(7)))(*args, s0)
+    for g in grads:
+        assert np.all(np.isfinite(np.asarray(g)))
+    y1, s1 = ref.mamba2_chunked_reference(*args, chunk=64, init_state=s0)
+    y2, s2 = ref.mamba2_scan_reference(*args, init_state=s0)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("chunk", [32, 64])
+@pytest.mark.parametrize("G", [1, 2])
+@pytest.mark.parametrize("with_state", [False, True])
+def test_mamba2_kernel_grads_match_reference(chunk, G, with_state):
+    """The kernel's custom vjp (the chunked reference, differentiated) gives
+    the sequential reference's gradients, at a length that does not tile
+    by the chunk."""
+    from repro.kernels import ops
+    args, s0 = _mamba2_inputs(1, 100, 2, 16, G, 16, with_state=with_state)
+    gy = jax.random.normal(jax.random.fold_in(KEY, 1), args[0].shape)
 
     def loss(scan):
         def f(*a):
@@ -134,10 +177,10 @@ def test_mamba2_kernel_grads_match_reference(with_state):
             return jnp.sum(y * gy) + jnp.sum(s)
         return f
 
-    args = (x, dt, A, Bm, Cm, D) + ((s0,) if with_state else ())
+    args = args + ((s0,) if with_state else ())
     n = len(args)
     g1 = jax.grad(loss(lambda *a: ops._mamba2_kernel_vjp(
-        *a[:6], 32, True, a[6] if with_state else None)),
+        *a[:6], chunk, True, a[6] if with_state else None)),
         argnums=tuple(range(n)))(*args)
     g2 = jax.grad(loss(lambda *a: ref.mamba2_scan_reference(
         *a[:6], init_state=a[6] if with_state else None)),

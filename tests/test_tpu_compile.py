@@ -123,6 +123,28 @@ def test_mamba2_ssd_zamba2(one_chip):
         "mamba2_ssd"}
 
 
+def test_mamba2_ssd_grad_zamba2_has_no_loop(one_chip):
+    """The zamba2_1p2b train cell's scan (4 x 512 tokens) forward and
+    backward: the kernel runs the forward, and the backward is the chunked
+    matmul form, with no per-step `while` loop."""
+    from repro.kernels import ops
+    spec = mamba_spec(get_config("zamba2_1p2b"))
+    H, P, G, N = spec.n_heads, spec.head_dim, spec.n_groups, spec.d_state
+    Bt, T = 4, 512
+
+    def loss(*a):
+        y, s = ops._mamba2_kernel_vjp(*a, 128, False, None)
+        return jnp.sum(y.astype(F32)) + jnp.sum(s)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((Bt, T, H, P), BF16), ((Bt, T, H), F32), ((H,), F32),
+        ((Bt, T, G, N), BF16), ((Bt, T, G, N), BF16), ((H,), F32))]
+    hlo = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))).lower(
+        *args).compile().as_text()
+    assert pallas_kernels(hlo) == {"mamba2_ssd"}
+    assert " while(" not in hlo
+
+
 def test_rwkv6_scan_rwkv6_7b(one_chip):
     from repro.kernels import rwkv6_scan as r6
     cfg = get_config("rwkv6_7b")
